@@ -24,6 +24,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import RaLMConfig, get_config, reduced  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
 from repro.retrieval.encoder import ContextEncoder  # noqa: E402
 from repro.retrieval.kb import DenseKB, SparseKB, build_knn_datastore  # noqa: E402
@@ -39,6 +40,9 @@ N_DOCS_SPARSE = 30_000
 KNN_ENTRIES = 1_000_000
 KNN_DIM = 128
 VOCAB = 50257   # gpt2-medium class host LM
+
+# every benchmark imports this module: one persistent compile cache for all
+enable_compile_cache()
 
 
 def _cached(name, builder):

@@ -56,7 +56,8 @@ from repro.core.cache import (DenseRetrievalCache, SharedCacheView,
                               query_key)
 from repro.core.scheduler import OS3
 from repro.retrieval.encoder import ContextEncoder
-from repro.retrieval.faults import RetrievalFailed, RetrievalTimeout
+from repro.retrieval.faults import (RetrievalFailed, RetrievalTimeout,
+                                    TransientRetrievalError)
 from repro.retrieval.retrievers import BM25Retriever
 
 
@@ -238,8 +239,11 @@ class _ServerBase:
         that overruns it completes, but its rows are discarded and the call
         retried, which the same determinism makes safe.
 
-        Raises :class:`~repro.retrieval.faults.RetrievalFailed` once the
-        budget is exhausted; the fleet round loop degrades gracefully.
+        Retries only the fault taxonomy of `repro.retrieval.faults`
+        (:class:`TransientRetrievalError`, :class:`RetrievalTimeout`); any
+        other exception propagates unchanged. Raises
+        :class:`~repro.retrieval.faults.RetrievalFailed` once the budget is
+        exhausted; the fleet round loop degrades gracefully.
         Failed attempts are charged to the analytic timeline at the modeled
         batched-call cost (plus any real backoff sleeps) via the
         ``_ft_overhead`` accumulator, and counted on ``RetrieverStats``."""
@@ -256,7 +260,9 @@ class _ServerBase:
             t0 = time.perf_counter()
             try:
                 ids, scores = self._retrieve_batch(queries, k)
-            except Exception as e:     # any backend fault is assumed transient
+            except (TransientRetrievalError, RetrievalTimeout) as e:
+                # only the fault taxonomy is retried; anything else (a
+                # compile error, a device OOM) is a bug and propagates
                 last = e
                 stats.record_failure("error", final=final)
                 with self._ft_lock:

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel body is
-semantically validated; on TPU the same calls compile to Mosaic. ``force_ref=True``
+On TPU the calls compile to Mosaic. On CPU, where the tests run, the kernels
+execute in interpret mode — the kernel body is semantically validated. Any
+other platform is an error, never a silent interpret. ``force_ref=True``
 routes to the pure-jnp oracle (used by retrievers when interpret overhead would
 dominate a wall-clock benchmark).
 """
@@ -23,7 +24,12 @@ from repro.kernels.dense_topk import (FUSED_BLOCK_C, dense_topk_pallas,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"no Pallas kernel path on platform {platform!r}: the kernels "
+            "compile for 'tpu' and run in interpret mode only on 'cpu'")
+    return platform == "cpu"
 
 
 @partial(jax.jit, static_argnames=("k", "force_ref"))
@@ -61,11 +67,11 @@ def fused_gathered_topk(queries: jax.Array, kb: jax.Array, cand: jax.Array,
                         force_ref: bool = False):
     """The fused-gather ADR/IVF probe: query b scores only the KB rows named
     by cand[b] ((B, C) int32, -1 = padding), and the candidate gather runs
-    INSIDE the kernel — each (block_c, d) tile DMAs from the resident KB per
-    grid step, so peak candidate scratch is B * block_c * d regardless of C
-    (no (B, C, d) materialization anywhere, including under ``force_ref``,
-    whose oracle streams the same tiles with a running top-k). Results are
-    byte-identical to :func:`gathered_topk`."""
+    INSIDE the kernel — each candidate's row DMAs from the resident KB, so
+    peak candidate scratch (`dense_topk.fused_scratch_bytes`) does not grow
+    with C (no (B, C, d) materialization anywhere, including under
+    ``force_ref``, whose oracle streams (B, block_c) id tiles with a running
+    top-k). Same candidates, ids and tie break as :func:`gathered_topk`."""
     if force_ref:
         return ref.fused_gathered_topk_ref(queries, kb, cand, k,
                                            block_c=block_c)
@@ -109,8 +115,8 @@ def quant_fused_gathered_topk(queries: jax.Array, kb_q: jax.Array,
     """Fused-gather form of :func:`quant_gathered_topk`: each candidate row's
     int8 codes AND fp32 scale DMA from the resident arrays inside the kernel
     — neither the (B, C, d) code gather nor the (B, C) scale gather
-    materializes; peak candidate scratch is B * block_c * (d + 4) bytes.
-    Byte-identical to :func:`quant_gathered_topk`."""
+    materializes. Same candidates, ids and tie break as
+    :func:`quant_gathered_topk`."""
     if force_ref:
         return ref.quant_fused_gathered_topk_ref(queries, kb_q, scales, cand,
                                                  k, block_c=block_c)

@@ -3,9 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --retriever edr --mode both \
         --requests 5 --variant psa
 
-Builds the synthetic Wikipedia-like corpus, the chosen retriever, a reduced GPT-2-
-class host LM, and serves QA-style requests with RaLMSeq (baseline) and/or RaLMSpec,
-printing the paper-style G/R latency decomposition and the speed-up ratio.
+Builds the synthetic Wikipedia-like corpus, the chosen retriever, a host LM
+(``--arch``, reduced to 2 layers unless ``--full-width`` serves the published
+config), and serves QA-style requests with RaLMSeq (baseline) and/or RaLMSpec,
+printing the paper-style G/R latency decomposition and the speed-up ratio. With
+``--mode both`` the run exits non-zero when the two disagree, and a request
+degraded with no injected faults does too.
 
 ``--concurrency N`` (N > 1) switches the speculative path to the fleet: a
 BatchedServeEngine with N slots and a FleetServer that serves requests in groups
@@ -45,7 +48,7 @@ requests with a ``shed`` status instead of queueing unboundedly:
 ``--retriever-backend {numpy,kernel,sharded,int8,int8-kernel,int8-sharded}``
 picks the dense retrievers' execution backend (`repro.retrieval.backends`):
 the flat numpy scan, the Pallas blocked top-k (`kernels/dense_topk`,
-interpret mode on CPU, Mosaic on TPU; KB resident on device), the
+compiled by Mosaic on TPU, interpret mode on CPU; KB resident on device), the
 mesh-sharded scan (`retrieval/sharded.py`) where every merged verification
 round is ONE collective over the KB shards — or their int8 quantized
 siblings, which hold the KB as per-row symmetric int8 codes + fp32 scales
@@ -55,9 +58,10 @@ of byte-parity, see docs/architecture.md). EDR delegates its full scan
 centroid scoring stays host-side, so the merged ADR probe is still one
 collective on the sharded backends, fp32 and int8 alike). SR has a single
 execution strategy (see ``BACKEND_SUPPORT``). ``--mesh-shards N`` sets the
-shard count — on a CPU host it forces an N-device host platform (XLA_FLAGS,
-applied below before jax initializes), simulating the multi-chip layout the
-sharded backends target:
+shard count, one shard per device: asking for more shards than there are
+devices is an error. On a CPU host it forces an N-device host platform
+(XLA_FLAGS, applied below before jax initializes), simulating the multi-chip
+layout the sharded backends target:
 
     PYTHONPATH=src python -m repro.launch.serve --concurrency 4 \
         --retriever-backend sharded --mesh-shards 4 --requests 4
@@ -78,6 +82,7 @@ bootstrap_mesh_shards()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import sys  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -86,6 +91,7 @@ from repro.configs import RaLMConfig, get_config, reduced
 from repro.core.cache import SharedRetrievalCache
 from repro.core.knnlm import KNNLMSeq, KNNLMSpec
 from repro.core.ralmspec import RaLMSeq, RaLMSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.retrieval.encoder import ContextEncoder
 from repro.retrieval.faults import inject_faults, parse_fault_spec
@@ -172,7 +178,8 @@ def build_stack(retriever: str, *, n_docs: int = 20000, arch: str = "ralm-gpt2-m
                 backend: str = "numpy", mesh_shards: int = 0, seed: int = 0,
                 enc_dim: int = 64, d_model: int = 256, workload: str = "ralm",
                 rcfg: RaLMConfig = None, shared_cache=None,
-                knn_entries: int = 20000) -> ServeStack:
+                knn_entries: int = 20000,
+                full_width: bool = False) -> ServeStack:
     """Model + corpus + retriever + workload for the serving drivers and
     benchmarks, validated against the capability table and returned as a
     :class:`ServeStack`. ``backend`` picks the dense retrievers' execution
@@ -180,7 +187,9 @@ def build_stack(retriever: str, *, n_docs: int = 20000, arch: str = "ralm-gpt2-m
     EDR's full scan and ADR's IVF bucket scan alike); ``mesh_shards`` caps
     the sharded backends' shard count (0 = one shard per visible device);
     ``enc_dim``/``d_model`` let benchmarks tune the retrieval-vs-LM cost
-    ratio (bench_async_fleet needs retrieval-heavy EDR).
+    ratio (bench_async_fleet needs retrieval-heavy EDR). The LM is ``arch``
+    reduced to 2 layers of width ``d_model``, or with ``full_width`` the
+    registry config as published (float32 weights from ``seed``).
 
     With ``workload='knnlm'`` the KB is a (context -> next token) datastore
     over the corpus token stream (``knn_entries`` caps its size; the stream
@@ -191,7 +200,9 @@ def build_stack(retriever: str, *, n_docs: int = 20000, arch: str = "ralm-gpt2-m
         rcfg = RaLMConfig(knnlm=(workload == "knnlm"))
     else:
         rcfg = dataclasses.replace(rcfg, knnlm=(workload == "knnlm"))
-    cfg = reduced(get_config(arch), layers=2, d_model=d_model)
+    cfg = get_config(arch)
+    if not full_width:
+        cfg = reduced(cfg, layers=2, d_model=d_model)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(seed))
     docs = synthetic_corpus(n_docs, cfg.vocab_size)
@@ -316,6 +327,11 @@ def main() -> None:
                          "knnlm: KNN-LM serving (per-token datastore "
                          "retrieval, token-match parity — paper §5.3)")
     ap.add_argument("--retriever", choices=["edr", "adr", "sr"], default="edr")
+    ap.add_argument("--arch", default="ralm-gpt2-medium",
+                    help="host LM from the config registry (repro.configs)")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve --arch at its published width and depth "
+                         "(default: a 2-layer reduced variant)")
     ap.add_argument("--mode", choices=["seq", "spec", "both"], default="both")
     ap.add_argument("--variant", default="psa",
                     help="subset of 'psa': prefetch / OS3 scheduler / async")
@@ -339,18 +355,18 @@ def main() -> None:
     ap.add_argument("--retriever-backend",
                     choices=list(BACKENDS), default="numpy",
                     help="dense scoring backend (EDR full scan / ADR bucket "
-                         "scan): numpy, the Pallas top-k kernel (interpret "
-                         "mode on CPU), the mesh-sharded scan (one "
-                         "collective per merged verification round), or "
-                         "their int8 quantized siblings int8/int8-kernel/"
-                         "int8-sharded (~4x less index memory, recall@k "
-                         "contract instead of byte-parity). SR supports "
-                         "numpy only")
+                         "scan): numpy, the Pallas top-k kernel (compiled "
+                         "for the TPU; interpret mode on CPU), the "
+                         "mesh-sharded scan (one collective per merged "
+                         "verification round), or their int8 quantized "
+                         "siblings int8/int8-kernel/int8-sharded (~4x less "
+                         "index memory, recall@k contract instead of "
+                         "byte-parity). SR supports numpy only")
     ap.add_argument("--mesh-shards", type=int, default=0,
-                    help="shard count for the sharded backends "
-                         "(0 = one shard per visible device; on CPU, N > 1 "
-                         "forces an N-device host platform before jax "
-                         "initializes)")
+                    help="shard count for the sharded backends, one per "
+                         "device (0 = every visible device; more shards than "
+                         "devices is an error; on CPU, N > 1 forces an "
+                         "N-device host platform before jax initializes)")
     ap.add_argument("--arrival-rate", type=float, default=0.0,
                     help="Poisson arrival rate, requests per modeled second "
                          "(0 = all requests arrive at t=0)")
@@ -438,10 +454,11 @@ def main() -> None:
                                      queue_deadline_s=args.queue_deadline))
     shared = (SharedRetrievalCache(capacity=args.shared_cache_capacity)
               if args.shared_cache else None)
+    enable_compile_cache()
     stack = build_stack(
         args.retriever, n_docs=args.n_docs, backend=args.retriever_backend,
         mesh_shards=args.mesh_shards, workload=args.workload, rcfg=rcfg,
-        shared_cache=shared)
+        shared_cache=shared, arch=args.arch, full_width=args.full_width)
     docs, retr = stack.docs, stack.retriever
     if args.retriever_backend != "numpy":
         b = retr.backend
@@ -461,6 +478,8 @@ def main() -> None:
                    for i in range(args.requests)]
     else:
         prompts = [(q * 12)[:48] for q in make_queries(docs, args.requests)]
+
+    degraded = []                           # requests served degraded
 
     def run(server, label):
         tot_w = tot_g = tot_r = 0.0
@@ -503,6 +522,8 @@ def main() -> None:
                 tot_an += fr.analytic_time
                 n_tok += fr.total_tokens
                 toks.extend(r.tokens for r in fr.results)
+                degraded.extend(r for r in fr.results
+                                if r.status == "degraded")
                 degradation_line(fr)
         print(f"{label:14s} wall {tot_w:7.2f}s  modeled {tot_an:6.2f}s  "
               f"throughput {n_tok / max(tot_an, 1e-9):8.1f} tok/s (modeled)")
@@ -519,6 +540,7 @@ def main() -> None:
               f"p50 {cr.p50:.2f}s  p99 {cr.p99:.2f}s  "
               f"peak live {cr.max_live}")
         degradation_line(cr)
+        degraded.extend(r for r in cr.results if r.status == "degraded")
         return cr.wall_time, [r.tokens for r in cr.results]
 
     knn = args.workload == "knnlm"
@@ -536,12 +558,20 @@ def main() -> None:
         else:
             results["spec"] = run(make_server(stack, scheduler="single"),
                                   label)
+    failures = []
     if len(results) == 2:
-        same = all(a == b for a, b in zip(results["seq"][1], results["spec"][1]))
+        same = results["seq"][1] == results["spec"][1]
         kind = ("outputs token-match" if stack.workload.equivalence ==
                 "token-match" else "outputs identical")
         print(f"{kind}: {same}   "
               f"speed-up {results['seq'][0] / max(results['spec'][0], 1e-9):.2f}x")
+        if not same:
+            failures.append(f"{kind}: False")
+    if degraded and inj is None:
+        # degradation is the documented answer to INJECTED faults only; with
+        # none injected, a degraded request is a failure the run must report
+        failures.append(f"{len(degraded)} request(s) degraded with no "
+                        "injected faults")
     if getattr(getattr(retr, "backend", None), "name", "").endswith("sharded"):
         # the merge invariant, visible: every KB call (seed or merged
         # verification round — EDR scan or ADR probe) executed as exactly one
@@ -558,6 +588,8 @@ def main() -> None:
               f"over {inj.calls} KB scans (seed {inj.spec.seed}); "
               f"retried {retr.stats.errors + retr.stats.timeouts} attempts, "
               f"{retr.stats.failed_calls} calls failed after retries")
+    if failures:
+        sys.exit("FAILED: " + "; ".join(failures))
 
 
 if __name__ == "__main__":

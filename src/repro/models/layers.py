@@ -16,39 +16,37 @@ from jax.sharding import PartitionSpec as P
 
 
 # --------------------------------------------------------------------------------------
-# sharding helper: constraint only when a mesh is in scope (no-op in plain CPU tests)
+# sharding helper: constraint only when a mesh is in scope (no-op without one)
 # --------------------------------------------------------------------------------------
 def shard(x: jax.Array, spec: P) -> jax.Array:
-    """Sharding constraint that degrades gracefully: axes missing from the current
-    mesh are dropped, and any spec entry whose mesh-axis product does not divide the
-    array dimension is dropped (e.g. KV=8 heads on a 16-way 'model' axis ->
-    replicated). Keeps one set of constraints valid across 1-device CPU tests, the
-    16x16 pod mesh and the 2x16x16 multi-pod mesh."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-
-        def _filter(entry, dim):
-            if entry is None:
-                return None
-            axes = entry if isinstance(entry, tuple) else (entry,)
-            kept = tuple(a for a in axes if a in sizes)
-            if not kept:
-                return None
-            prod = 1
-            for a in kept:
-                prod *= sizes[a]
-            if dim % prod != 0:
-                return None
-            return kept if len(kept) > 1 else kept[0]
-
-        entries = list(spec) + [None] * (x.ndim - len(spec))
-        spec = P(*[_filter(e, x.shape[i]) for i, e in enumerate(entries[: x.ndim])])
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    """Sharding constraint that adapts to the active mesh: with no mesh it is
+    a no-op; axes missing from the mesh are dropped, and any spec entry whose
+    mesh-axis product does not divide the array dimension is dropped (e.g.
+    KV=8 heads on a 16-way 'model' axis -> replicated). Keeps one set of
+    constraints valid across 1-device serving, the 16x16 pod mesh and the
+    2x16x16 multi-pod mesh. Any error from the constraint itself propagates."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return x
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+
+    def _filter(entry, dim):
+        if entry is None:
+            return None
+        axes = entry if isinstance(entry, tuple) else (entry,)
+        kept = tuple(a for a in axes if a in sizes)
+        if not kept:
+            return None
+        prod = 1
+        for a in kept:
+            prod *= sizes[a]
+        if dim % prod != 0:
+            return None
+        return kept if len(kept) > 1 else kept[0]
+
+    entries = list(spec) + [None] * (x.ndim - len(spec))
+    spec = P(*[_filter(e, x.shape[i]) for i, e in enumerate(entries[: x.ndim])])
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def batch_axes(mesh_axis_names) -> tuple:
@@ -348,12 +346,8 @@ def apply_self_attention(p, cfg, x, positions, *, causal=True, window=0,
 
 
 def _mesh_active() -> bool:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        return mesh is not None and not mesh.empty and len(mesh.axis_names) > 0 \
-            and any(int(s) > 1 for s in mesh.axis_sizes)
-    except Exception:
-        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    return not mesh.empty and any(int(s) > 1 for s in mesh.axis_sizes)
 
 
 def apply_self_attention_decode(p, cfg, x, position, k_cache, v_cache, cache_len,

@@ -342,9 +342,9 @@ class KernelBackend(_JitShapeMixin):
     HBM -> VMEM, the query block stays MXU-resident. The KB embedding matrix
     is put on device ONCE here — per-call uploads of a multi-GB index would
     dwarf the scan itself. The gathered (ADR) scan routes through the FUSED
-    in-kernel gather (`kernels.ops.fused_gathered_topk`): candidate rows DMA
-    from the resident KB per (B, block_c, d) tile, so no (B, C, d) tensor
-    materializes however wide the probe. ``force_ref=True`` swaps the kernel
+    in-kernel gather (`kernels.ops.fused_gathered_topk`): each candidate's
+    aligned row group DMAs from the resident KB into a small staging ring,
+    so no (B, C, d) tensor materializes however wide the probe. ``force_ref=True`` swaps the kernel
     bodies for their jnp oracles (same results — the fused oracle streams the
     same tiles; wall-clock benchmarks use it off-TPU, where interpret-mode
     overhead would swamp the numbers)."""
@@ -368,11 +368,28 @@ class KernelBackend(_JitShapeMixin):
         self._init_shapes(self._kb.shape[0])
 
     def gathered_scratch_bytes(self, B: int, C: int) -> int:
-        from repro.kernels.dense_topk import fused_block_c
-        return B * fused_block_c(C, self._block_c) * self._kb.shape[1] * 4
+        from repro.kernels.dense_topk import fused_scratch_bytes
+        return fused_scratch_bytes(B, C, self._kb.shape[1], self._kb.dtype,
+                                   self._block_c)
 
     def pregathered_scratch_bytes(self, B: int, C: int) -> int:
         return B * C * self._kb.shape[1] * 4
+
+    def program_text(self, B: int, k: int, C: Optional[int] = None) -> str:
+        """The lowered program ``search`` runs at batch B (with a candidate
+        width C: ``search_gathered``'s) — on TPU it holds the Pallas kernel
+        as a ``tpu_custom_call``, which is how a caller checks that the
+        kernel itself, and not an interpreter or oracle, serves the call."""
+        import jax
+        import jax.numpy as jnp
+        q = jax.ShapeDtypeStruct((B, self._kb.shape[1]), jnp.float32)
+        if C is None:
+            return self._fn.lower(q, self._kb, min(k, self._kb.shape[0]),
+                                  force_ref=self._force_ref).as_text()
+        cand = jax.ShapeDtypeStruct((B, C), jnp.int32)
+        return self._fn_gathered.lower(q, self._kb, cand, min(k, C),
+                                       block_c=self._block_c,
+                                       force_ref=self._force_ref).as_text()
 
     def search(self, queries: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
         import jax.numpy as jnp
@@ -418,7 +435,6 @@ class ShardedBackend(_JitShapeMixin):
                  axis: str = "data", mesh=None,
                  block_c: Optional[int] = None):
         import jax
-        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.kernels.dense_topk import FUSED_BLOCK_C
@@ -427,7 +443,11 @@ class ShardedBackend(_JitShapeMixin):
         self._block_c = block_c or FUSED_BLOCK_C
         if mesh is None:
             devs = jax.devices()
-            n = len(devs) if not n_shards else min(n_shards, len(devs))
+            if n_shards and n_shards > len(devs):
+                raise ValueError(
+                    f"{n_shards} KB shards requested but only {len(devs)} "
+                    f"{devs[0].platform} device(s) are visible")
+            n = n_shards or len(devs)
             mesh = jax.sharding.Mesh(np.asarray(devs[:n]), (axis,))
         self.mesh, self.axis = mesh, axis
         self.n_shards = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
@@ -439,10 +459,11 @@ class ShardedBackend(_JitShapeMixin):
             matrix = np.pad(matrix, ((0, pad), (0, 0)))
             if scales is not None:
                 scales = np.pad(scales, ((0, pad),))
-        self._kb = jax.device_put(jnp.asarray(matrix),
-                                  NamedSharding(mesh, P(axis, None)))
+        # straight from host memory: each device receives only its shard
+        # (a jnp array here would first land whole on the default device)
+        self._kb = jax.device_put(matrix, NamedSharding(mesh, P(axis, None)))
         self._scales = None if scales is None else jax.device_put(
-            jnp.asarray(scales), NamedSharding(mesh, P(axis)))
+            scales, NamedSharding(mesh, P(axis)))
         self.kb_bytes = matrix.nbytes + (0 if scales is None else scales.nbytes)
         self.calls = 0
         self._init_shapes(self.n_total)
@@ -486,10 +507,9 @@ class ShardedBackend(_JitShapeMixin):
                         + (4 if self._scales is not None else 0))
 
     def search(self, queries: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        import jax
         import jax.numpy as jnp
-
-        from repro.retrieval.sharded import mesh_context
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             scores, gids = self._scan(jnp.asarray(queries, jnp.float32),
                                       self._kb, self._scales,
                                       min(k, self.n_total))
@@ -498,10 +518,9 @@ class ShardedBackend(_JitShapeMixin):
 
     def search_gathered(self, queries: np.ndarray, cand: np.ndarray,
                         k: int) -> Tuple[np.ndarray, np.ndarray]:
+        import jax
         import jax.numpy as jnp
-
-        from repro.retrieval.sharded import mesh_context
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             scores, gids = self._scan_gathered(
                 jnp.asarray(queries, jnp.float32), self._kb, self._scales,
                 jnp.asarray(cand, jnp.int32), min(k, cand.shape[1]))
@@ -590,9 +609,9 @@ class QuantizedKernelBackend(_JitShapeMixin):
         self._init_shapes(codes.shape[0])
 
     def gathered_scratch_bytes(self, B: int, C: int) -> int:
-        from repro.kernels.dense_topk import fused_block_c
-        bc = fused_block_c(C, self._block_c)
-        return B * bc * (self._kb.shape[1] + 4)     # int8 tile + f32 scales
+        from repro.kernels.dense_topk import fused_scratch_bytes
+        return fused_scratch_bytes(B, C, self._kb.shape[1], self._kb.dtype,
+                                   self._block_c, quant=True)
 
     def pregathered_scratch_bytes(self, B: int, C: int) -> int:
         return B * C * (self._kb.shape[1] + 4)

@@ -24,7 +24,6 @@ concatenate in shard order).
 """
 from __future__ import annotations
 
-from contextlib import nullcontext
 from functools import partial
 from typing import Optional
 
@@ -34,23 +33,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.dense_topk import NEG  # one pad sentinel, every backend
 
-# jax moved shard_map out of experimental and renamed check_rep -> check_vma;
-# support both spellings so the seed toolchain (0.4.x) and current jax run this.
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:                                        # pragma: no cover - jax>=0.6 path
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def mesh_context(mesh):
-    """Context manager activating `mesh` across jax versions (set_mesh /
-    use_mesh / no-op — shard_map takes the mesh explicitly anyway)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return nullcontext()
+# f32 scores at full f32 precision on every platform: the sharded scan is an
+# exact backend, held to byte parity with the numpy scan
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def sharded_dense_topk(queries: jax.Array, kb: jax.Array, k: int, mesh,
@@ -92,7 +77,7 @@ def sharded_dense_topk(queries: jax.Array, kb: jax.Array, k: int, mesh,
         kb2 = kb_shard[0] if kb_shard.ndim == 3 else kb_shard
         shard_idx = jax.lax.axis_index(axis)
         s_full = jnp.einsum("bd,nd->bn", q.astype(jnp.float32),
-                            kb2.astype(jnp.float32))
+                            kb2.astype(jnp.float32), precision=_HIGHEST)
         if scl_shard is not None:
             scl2 = scl_shard[0] if scl_shard.ndim == 2 else scl_shard
             s_full = s_full * scl2.astype(jnp.float32)[None, :]
@@ -115,18 +100,18 @@ def sharded_dense_topk(queries: jax.Array, kb: jax.Array, k: int, mesh,
     # outputs are replicated by construction (all_gather + identical top_k on
     # every shard); the varying-axis inference can't see through axis_index
     if scales is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda q, kb_shard: local(q, kb_shard, None), mesh=mesh,
             in_specs=(P(), P(axis, None)),
             out_specs=(P(), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         return fn(queries, kb)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis)),
         out_specs=(P(), P()),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(queries, kb, scales)
 
@@ -206,7 +191,8 @@ def sharded_gathered_topk(queries: jax.Array, kb: jax.Array, cand: jax.Array,
         def score_chunk(ch):                   # (B, bc) ids -> (B, bc) f32
             idx = jnp.clip(ch - lo, 0, shard_n - 1)
             emb = jnp.take(kb2, idx, axis=0)   # (B, bc, d): the ONLY gather
-            s = jnp.einsum("bcd,bd->bc", emb.astype(jnp.float32), qf)
+            s = jnp.einsum("bcd,bd->bc", emb.astype(jnp.float32), qf,
+                           precision=_HIGHEST)
             if scl2 is not None:
                 s = s * jnp.take(scl2, idx, axis=0).astype(jnp.float32)
             return s
@@ -228,18 +214,18 @@ def sharded_gathered_topk(queries: jax.Array, kb: jax.Array, cand: jax.Array,
         return top_s, top_g
 
     if scales is None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda q, cd, kb_shard: local(q, cd, kb_shard, None), mesh=mesh,
             in_specs=(P(), P(), P(axis, None)),
             out_specs=(P(), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         return fn(queries, cand.astype(jnp.int32), kb)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axis, None), P(axis)),
         out_specs=(P(), P()),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(queries, cand.astype(jnp.int32), kb, scales)
 
@@ -250,6 +236,6 @@ def lower_sharded_retrieval(mesh, *, n_docs: int = 1_048_576, d: int = 256,
     q = jax.ShapeDtypeStruct((batch, d), jnp.float32)
     kb = jax.ShapeDtypeStruct((n_docs, d), jnp.float32)
     fn = partial(sharded_dense_topk, k=k, mesh=mesh, axis=axis)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(q, kb)
         return lowered.compile()

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import Model
-from repro.serving.engine import EngineStats
+from repro.serving.engine import EngineStats, lm_programs
 
 
 def _row_mask(mask: jax.Array, leaf: jax.Array) -> jax.Array:
@@ -75,11 +75,8 @@ class BatchedServeEngine:
         self.eos_id = eos_id
         self.extra = extra
         self.stats = EngineStats()
-        self._decode_jit = jax.jit(
-            lambda p, st, tok, pos: model.decode_step(p, st, tok, pos))
-        self._prefill_jit = jax.jit(
-            lambda p, toks: model.prefill(p, toks, extra=extra,
-                                          window_cache=self.W))
+        self._decode_jit, self._prefill_jit = lm_programs(model, self.W,
+                                                          extra)
         # scatter one prefilled row into the batched bundle / restore one row
         # from a snapshot bundle / commit rows by mask — all jitted once, with a
         # traced slot index so no per-slot recompiles

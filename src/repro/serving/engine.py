@@ -26,6 +26,25 @@ import numpy as np
 from repro.models.model import Model
 
 
+def lm_programs(model: Model, cache_window: int, extra: Optional[dict]):
+    """The jitted (decode, prefill) pair every engine serves with.
+
+    The decode step's matmuls run at full f32 precision on every platform.
+    On a TPU the default reads f32 operands as one bf16 pass; the
+    sequential baseline decodes at batch 1 and the fleet at batch N, two
+    different programs, and at that precision they can round a logit
+    differently and pick another greedy token — breaking output
+    preservation. Prefill always runs at batch 1, one program for both."""
+    def decode(p, st, tok, pos):
+        with jax.default_matmul_precision("highest"):
+            return model.decode_step(p, st, tok, pos)
+
+    def prefill(p, toks):
+        return model.prefill(p, toks, extra=extra, window_cache=cache_window)
+
+    return jax.jit(decode), jax.jit(prefill)
+
+
 @dataclass
 class EngineStats:
     prefill_time: float = 0.0
@@ -53,11 +72,8 @@ class ServeEngine:
         self.eos_id = eos_id
         self.extra = extra
         self.stats = EngineStats()
-        self._decode_jit = jax.jit(
-            lambda p, st, tok, pos: model.decode_step(p, st, tok, pos))
-        self._prefill_jit = jax.jit(
-            lambda p, toks: model.prefill(p, toks, extra=extra,
-                                          window_cache=self.W))
+        self._decode_jit, self._prefill_jit = lm_programs(model, self.W,
+                                                          extra)
         # mutable per-request state
         self.doc: Tuple[int, ...] = ()
         self.tokens: List[int] = []        # prompt + generated (doc NOT included)

@@ -481,15 +481,16 @@ class FleetServer(_ServerBase):
                         self.overlap_wall += t_ov1 - t_ov0
                         self.overlap_measured += max(
                             0.0, min(v1, t_ov1) - max(v0, t_ov0))
-                except Exception:
+                except RetrievalFailed:
                     # worker crash recovery: the in-flight verification died
-                    # (RetrievalFailed after its retries, or anything else the
-                    # worker hit). Discard the overlapped stride exactly as a
-                    # rollback would — restoring each slot's first overlap
-                    # snapshot rewinds the tentative steps — then fall back to
-                    # a synchronous verification round below, which gets a
-                    # fresh retry budget. The round, not the server, dies
-                    # last: only a failed *synchronous* call degrades.
+                    # (RetrievalFailed after its retries; any other exception
+                    # is a bug and propagates). Discard the overlapped stride
+                    # exactly as a rollback would — restoring each slot's
+                    # first overlap snapshot rewinds the tentative steps —
+                    # then fall back to a synchronous verification round
+                    # below, which gets a fresh retry budget. The round, not
+                    # the server, dies last: only a failed *synchronous* call
+                    # degrades.
                     fleet.worker_crashes += 1
                     for b, steps in overlap.items():
                         eng.restore(b, steps[0][0])

@@ -92,12 +92,12 @@ def test_state_specs_kv_modes():
 
 def test_sharded_retrieval_matches_ref():
     from repro.kernels.ref import dense_topk_ref
-    from repro.retrieval.sharded import mesh_context, sharded_dense_topk
+    from repro.retrieval.sharded import sharded_dense_topk
     mesh = make_local_mesh()
     kq, kk = jax.random.split(jax.random.PRNGKey(0))
     q = jax.random.normal(kq, (4, 32))
     kb = jax.random.normal(kk, (1000, 32))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         s1, g1 = sharded_dense_topk(q, kb, 8, mesh, axis="model")
     s2, g2 = dense_topk_ref(q, kb, 8)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-5)
@@ -115,7 +115,7 @@ def test_dryrun_pair_subprocess():
         "print('DRYRUN_OK' if r['ok'] else r['error'])"
     )
     env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"      # a test child never reaches for a chip
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=300, env=env)
     assert "DRYRUN_OK" in out.stdout, out.stdout + out.stderr
