@@ -28,6 +28,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import RaLMConfig
 from repro.core.cache import DenseRetrievalCache
 from repro.core.ralmspec import ServeResult
@@ -38,21 +39,24 @@ from repro.retrieval.encoder import ContextEncoder
 def knn_interpolate(lm_logits: np.ndarray, values: np.ndarray, scores: np.ndarray,
                     lam: float, beta: float = 8.0) -> int:
     """argmax of (1-lam)*softmax(lm) + lam*p_knn, p_knn = softmax(beta*scores) mass
-    scattered onto each neighbour's target token. Deterministic given inputs."""
-    V = lm_logits.shape[-1]
-    x = lm_logits.astype(np.float64)
-    x = x - x.max()
-    p_lm = np.exp(x)
-    p_lm /= p_lm.sum()
-    valid = values >= 0
-    p_knn = np.zeros(V, np.float64)
-    if valid.any():
-        s = scores[valid].astype(np.float64) * beta
-        s = np.exp(s - s.max())
-        s /= s.sum()
-        np.add.at(p_knn, values[valid], s)
-    p = (1.0 - lam) * p_lm + lam * p_knn
-    return int(np.argmax(p))
+    scattered onto each neighbour's target token. Deterministic given inputs.
+    The ``knn.interpolate`` span covers the body alone: whatever wraps a call
+    (the callers' phase spans, a benchmark's hooks) stays outside it."""
+    with obs.span("knn.interpolate"):
+        V = lm_logits.shape[-1]
+        x = lm_logits.astype(np.float64)
+        x = x - x.max()
+        p_lm = np.exp(x)
+        p_lm /= p_lm.sum()
+        valid = values >= 0
+        p_knn = np.zeros(V, np.float64)
+        if valid.any():
+            s = scores[valid].astype(np.float64) * beta
+            s = np.exp(s - s.max())
+            s /= s.sum()
+            np.add.at(p_knn, values[valid], s)
+        p = (1.0 - lam) * p_lm + lam * p_knn
+        return int(np.argmax(p))
 
 
 class KNNLMBase:

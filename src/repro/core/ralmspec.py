@@ -50,6 +50,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import RaLMConfig
 from repro.core.cache import (DenseRetrievalCache, SharedCacheView,
                               SharedRetrievalCache, SparseRetrievalCache,
@@ -85,6 +86,17 @@ class ServeResult:
     # mirroring the quantized backends' exact-bit pattern) | 'shed' (retired
     # by continuous-batching load shedding before serving a single token)
     status: str = "ok"
+    # wall-clock timeline on time.perf_counter's clock (fleet paths): when
+    # the request won a slot, each verified stride's commit, and retirement
+    t_admit: Optional[float] = None
+    t_retire: Optional[float] = None
+    commit_times: List[float] = field(default_factory=list)
+
+    @property
+    def t_first_token(self) -> Optional[float]:
+        """Commit of the first verified stride: the first output token a
+        client may be sent (every committed stride holds at least one)."""
+        return self.commit_times[0] if self.commit_times else None
 
     @property
     def ok(self) -> bool:
@@ -324,10 +336,11 @@ class _ServerBase:
         ids_row = [int(i) for i in ids_row if int(i) >= 0]
         if not ids_row:
             return
-        if self.sparse:
-            cache.insert(ids_row)
-        else:
-            cache.insert(ids_row, self.retriever.keys_of(ids_row))
+        with obs.span("commit.cache_insert"):
+            if self.sparse:
+                cache.insert(ids_row)
+            else:
+                cache.insert(ids_row, self.retriever.keys_of(ids_row))
 
     def _new_request_state(self, cache=None, rid: int = -1,
                            max_new: Optional[int] = None) -> RequestState:

@@ -100,6 +100,6 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array
             pltpu.VMEM((KV, G), jnp.float32),
             pltpu.VMEM((KV, G), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="decode_attention",
     )(cache_len, qg, k_cache, v_cache)
     return out.reshape(B, H, hd)

@@ -205,7 +205,7 @@ def gathered_topk_pallas(queries: jax.Array, cand_emb: jax.Array,
             pl.BlockSpec((B, block_c), lambda j: (0, j)),
         ],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=interpret, name="gathered_topk",
     )(queries, cand_emb, cand)
 
 
@@ -218,19 +218,21 @@ def dense_topk_pallas(queries: jax.Array, kb: jax.Array, k: int, *,
     nb = -(-N // block_n)
     pad = nb * block_n - N
     if pad:
-        kb = jnp.pad(kb, ((0, pad), (0, 0)))
+        with jax.named_scope("kb_pad"):
+            kb = jnp.pad(kb, ((0, pad), (0, 0)))
 
     out_shape, out_specs, scratch = _topk_outputs(B, k)
-    return pl.pallas_call(
-        functools.partial(_topk_kernel, k=k, block_n=block_n, n_total=N),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((B, d), lambda j: (0, 0)),          # queries resident
-            pl.BlockSpec((block_n, d), lambda j: (j, 0)),    # KB tile stream
-        ],
-        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret,
-    )(queries, kb)
+    with jax.named_scope("kb_scan"):
+        return pl.pallas_call(
+            functools.partial(_topk_kernel, k=k, block_n=block_n, n_total=N),
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((B, d), lambda j: (0, 0)),      # queries resident
+                pl.BlockSpec((block_n, d), lambda j: (j, 0)),  # KB tile stream
+            ],
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret, name="dense_topk",
+        )(queries, kb)
 
 
 def _quant_topk_kernel(q_ref, kbq_ref, scale_ref, out_s_ref, out_i_ref,
@@ -295,7 +297,7 @@ def quant_topk_pallas(queries: jax.Array, kb_q: jax.Array, scales: jax.Array,
             pl.BlockSpec((1, block_n), lambda j: (0, j)),    # row scales
         ],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=interpret, name="quant_topk",
     )(queries, kb_q, scales)
 
 
@@ -326,7 +328,7 @@ def quant_gathered_topk_pallas(queries: jax.Array, cand_emb: jax.Array,
             pl.BlockSpec((B, block_c), lambda j: (0, j)),
         ],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=interpret, name="quant_gathered_topk",
     )(queries, cand_emb, cand_scl, cand)
 
 
@@ -497,6 +499,7 @@ def _fused_call(queries, kb, scales, cand, k, block_c, interpret):
         scratch_shapes=staging + scratch
         + [pltpu.SemaphoreType.DMA((2, GATHER_SLOTS))],
         interpret=interpret,
+        name="quant_fused_gathered_topk" if quant else "fused_gathered_topk",
     )(*operands)
 
 

@@ -132,7 +132,7 @@ def prefill_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, G), jnp.float32),
             pltpu.VMEM((bq, G), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="prefill_attention",
     )(qf, kf, vf)
     out = out.reshape(B, KV, nq * bq, G, hd).transpose(0, 2, 1, 3, 4) \
              .reshape(B, nq * bq, H, hd)

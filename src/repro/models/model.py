@@ -88,8 +88,10 @@ def _apply_block(bp, cfg, sig: Sig, x, positions, *, mem=None, window=0,
     aux = jnp.zeros((), jnp.float32)
     h = L.rms_norm(x, bp["norm1"], cfg.norm_eps)
     if kind == "attn":
-        h = L.apply_self_attention(bp["mixer"], cfg, h, positions, causal=True,
-                                   window=window, prefix_len=prefix_len)
+        with jax.named_scope("attention"):
+            h = L.apply_self_attention(bp["mixer"], cfg, h, positions,
+                                       causal=True, window=window,
+                                       prefix_len=prefix_len)
     elif kind == "mamba":
         h = SSM.apply_mamba(bp["mixer"], cfg, h)
     elif kind == "mlstm":
@@ -110,7 +112,8 @@ def _apply_block(bp, cfg, sig: Sig, x, positions, *, mem=None, window=0,
         x = x + h
     elif "ffn" in bp:
         h = L.rms_norm(x, bp["norm2"], cfg.norm_eps)
-        x = x + L.apply_mlp(bp["ffn"], h)
+        with jax.named_scope("mlp"):
+            x = x + L.apply_mlp(bp["ffn"], h)
     x = shard(x, P(("pod", "data"), None, None))
     return x, aux
 
@@ -131,8 +134,10 @@ def _apply_block_decode(bp, cfg, sig: Sig, x, state, pos, write_idx, cache_len,
     kind, has_moe = sig
     h = L.rms_norm(x, bp["norm1"], cfg.norm_eps)
     if kind == "attn":
-        h, k_new, v_new = L.apply_self_attention_decode(
-            bp["mixer"], cfg, h, pos, state["k"], state["v"], cache_len, write_idx)
+        with jax.named_scope("attention"):
+            h, k_new, v_new = L.apply_self_attention_decode(
+                bp["mixer"], cfg, h, pos, state["k"], state["v"], cache_len,
+                write_idx)
         state = dict(state, k=k_new, v=v_new)
     elif kind == "mamba":
         h, st = SSM.apply_mamba_step(bp["mixer"], cfg, h, state["ssm"])
@@ -159,7 +164,8 @@ def _apply_block_decode(bp, cfg, sig: Sig, x, state, pos, write_idx, cache_len,
         x = x + h
     elif "ffn" in bp:
         h = L.rms_norm(x, bp["norm2"], cfg.norm_eps)
-        x = x + L.apply_mlp(bp["ffn"], h)
+        with jax.named_scope("mlp"):
+            x = x + L.apply_mlp(bp["ffn"], h)
     return x, state
 
 
@@ -376,7 +382,8 @@ class Model:
             new_state.append(st)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-        logits = jnp.einsum("bsd,dv->bsv", x, unembed)[:, 0]
+        with jax.named_scope("unembed"):
+            logits = jnp.einsum("bsd,dv->bsv", x, unembed)[:, 0]
         return logits, new_state
 
     @staticmethod
@@ -464,7 +471,8 @@ class Model:
         for i, sig in enumerate(sigs):
             bp = self._layer_params(params, i)
             if sig[0] == "attn":
-                k, v = _collect_kv(bp, cfg, x, positions)
+                with jax.named_scope("attention"):
+                    k, v = _collect_kv(bp, cfg, x, positions)
                 take = min(W, S)
                 kk = k[:, -take:].astype(state[i]["k"].dtype)
                 vv = v[:, -take:].astype(state[i]["v"].dtype)
@@ -488,7 +496,8 @@ class Model:
 
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-        logits = jnp.einsum("bsd,dv->bsv", x, unembed)
+        with jax.named_scope("unembed"):
+            logits = jnp.einsum("bsd,dv->bsv", x, unembed)
         return logits[:, -1], state, jnp.int32(S)
 
 

@@ -72,6 +72,8 @@ from typing import Optional, Protocol, Tuple, runtime_checkable
 
 import numpy as np
 
+from repro import obs
+
 
 def bootstrap_mesh_shards() -> None:
     """``--mesh-shards N`` needs N host-platform devices, and XLA only reads
@@ -399,7 +401,8 @@ class KernelBackend(_JitShapeMixin):
                                min(k, self._kb.shape[0]),
                                force_ref=self._force_ref)
         self.calls += 1
-        return np.asarray(ids, np.int64), np.asarray(scores, np.float32)
+        with obs.span("device.wait"):
+            return np.asarray(ids, np.int64), np.asarray(scores, np.float32)
 
     def search_gathered(self, queries: np.ndarray, cand: np.ndarray,
                         k: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -411,7 +414,8 @@ class KernelBackend(_JitShapeMixin):
                                         block_c=self._block_c,
                                         force_ref=self._force_ref)
         self.calls += 1
-        return _sentinels_to_contract(ids, scores)
+        with obs.span("device.wait"):
+            return _sentinels_to_contract(ids, scores)
 
 
 class ShardedBackend(_JitShapeMixin):
@@ -514,7 +518,8 @@ class ShardedBackend(_JitShapeMixin):
                                       self._kb, self._scales,
                                       min(k, self.n_total))
         self.calls += 1
-        return np.asarray(gids, np.int64), np.asarray(scores, np.float32)
+        with obs.span("device.wait"):
+            return np.asarray(gids, np.int64), np.asarray(scores, np.float32)
 
     def search_gathered(self, queries: np.ndarray, cand: np.ndarray,
                         k: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -525,7 +530,8 @@ class ShardedBackend(_JitShapeMixin):
                 jnp.asarray(queries, jnp.float32), self._kb, self._scales,
                 jnp.asarray(cand, jnp.int32), min(k, cand.shape[1]))
         self.calls += 1
-        return _sentinels_to_contract(gids, scores)
+        with obs.span("device.wait"):
+            return _sentinels_to_contract(gids, scores)
 
 
 class QuantizedFlatBackend:
@@ -622,7 +628,8 @@ class QuantizedKernelBackend(_JitShapeMixin):
                                self._kb_scales, min(k, self._kb.shape[0]),
                                force_ref=self._force_ref)
         self.calls += 1
-        return np.asarray(ids, np.int64), np.asarray(scores, np.float32)
+        with obs.span("device.wait"):
+            return np.asarray(ids, np.int64), np.asarray(scores, np.float32)
 
     def search_gathered(self, queries: np.ndarray, cand: np.ndarray,
                         k: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -634,7 +641,8 @@ class QuantizedKernelBackend(_JitShapeMixin):
                                         block_c=self._block_c,
                                         force_ref=self._force_ref)
         self.calls += 1
-        return _sentinels_to_contract(ids, scores)
+        with obs.span("device.wait"):
+            return _sentinels_to_contract(ids, scores)
 
 
 class QuantizedShardedBackend(ShardedBackend):

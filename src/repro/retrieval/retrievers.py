@@ -39,6 +39,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.retrieval.backends import (DenseSearchBackend, canonical_topk,
                                       make_backend)
 from repro.retrieval.kb import DenseKB, SparseKB
@@ -150,11 +151,13 @@ class _TimedRetriever:
         raise NotImplementedError
 
     def retrieve(self, queries, k: int) -> Tuple[np.ndarray, np.ndarray]:
-        queries = self._prep(queries)
-        warmup = self._cold_shape(len(queries), k)
-        t0 = time.perf_counter()
-        ids, scores = self._search(queries, k)
-        self.stats.add(len(queries), time.perf_counter() - t0, warmup=warmup)
+        with obs.span("kb.call"):
+            queries = self._prep(queries)
+            warmup = self._cold_shape(len(queries), k)
+            t0 = time.perf_counter()
+            ids, scores = self._search(queries, k)
+            self.stats.add(len(queries), time.perf_counter() - t0,
+                           warmup=warmup)
         return ids, scores
 
 

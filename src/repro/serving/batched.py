@@ -47,13 +47,13 @@ confirms, token-for-token.
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.model import Model
 from repro.serving.engine import EngineStats, lm_programs
 
@@ -150,16 +150,17 @@ class BatchedServeEngine:
         self.admit(slot, prompt, doc)
 
     def _prefill_slot(self, slot: int) -> None:
-        t0 = time.perf_counter()
-        seq = list(self.doc[slot]) + self.tokens[slot]
-        toks = jnp.asarray(np.asarray(seq, np.int32))[None]
-        last, state, pos = self._prefill_jit(self.params, toks)
-        b = jnp.int32(slot)
-        self._state = self._scatter_jit(self._state, state, b)
-        self._pos = self._pos.at[slot].set(pos)
-        self._last_logits = self._last_logits.at[slot].set(last[0])
-        jax.block_until_ready(self._last_logits)
-        self.stats.prefill_time += time.perf_counter() - t0
+        with obs.span("engine.prefill") as sp:
+            seq = list(self.doc[slot]) + self.tokens[slot]
+            toks = jnp.asarray(np.asarray(seq, np.int32))[None]
+            last, state, pos = self._prefill_jit(self.params, toks)
+            b = jnp.int32(slot)
+            self._state = self._scatter_jit(self._state, state, b)
+            self._pos = self._pos.at[slot].set(pos)
+            self._last_logits = self._last_logits.at[slot].set(last[0])
+            with obs.span("device.wait"):
+                jax.block_until_ready(self._last_logits)
+        self.stats.prefill_time += sp.seconds
         self.stats.prefills += 1
 
     def set_doc(self, slot: int, doc: Sequence[int]) -> None:
@@ -177,45 +178,47 @@ class BatchedServeEngine:
         Returns the new tokens per requested slot."""
         assert all(self.active[int(b)] for b in slots), \
             f"gen over idle slot(s): {[int(b) for b in slots if not self.active[int(b)]]}"
-        t0 = time.perf_counter()
-        remaining = {int(b): int(k) for b, k in zip(slots, ks)}
-        out = {int(b): [] for b in slots}
-        live = [b for b, k in remaining.items() if k > 0]
-        committed = self._bundle()
-        current = committed
-        while live:
-            state, pos, logits = current
-            next_tok = np.asarray(jnp.argmax(logits, axis=-1))
-            eos_exits, budget_exits = [], []
-            tok_vec = np.zeros((self.n_slots,), np.int32)
-            for b in live:
-                t = int(next_tok[b])
-                out[b].append(t)
-                self.tokens[b].append(t)
-                if t == self.eos_id:
-                    eos_exits.append(b)     # EOS: no decode for this token
-                    continue
-                tok_vec[b] = t
-                remaining[b] -= 1
-                if remaining[b] <= 0:
-                    budget_exits.append(b)  # budget: commit *after* this decode
-            if eos_exits:
-                committed = self._commit_bundle(current, committed, eos_exits)
-                live = [b for b in live if b not in eos_exits]
-                if not live:
-                    break
-            logits2, state2 = self._decode_jit(
-                self.params, state, jnp.asarray(tok_vec), pos)
-            live_mask = np.zeros((self.n_slots,), bool)
-            live_mask[live] = True
-            pos2 = pos + jnp.asarray(live_mask, jnp.int32)
-            current = (state2, pos2, logits2)
-            if budget_exits:
-                committed = self._commit_bundle(current, committed, budget_exits)
-                live = [b for b in live if b not in budget_exits]
-        self._set_bundle(committed)
-        jax.block_until_ready(self._last_logits)
-        self.stats.decode_time += time.perf_counter() - t0
+        with obs.span("engine.decode") as sp:
+            remaining = {int(b): int(k) for b, k in zip(slots, ks)}
+            out = {int(b): [] for b in slots}
+            live = [b for b, k in remaining.items() if k > 0]
+            committed = self._bundle()
+            current = committed
+            while live:
+                state, pos, logits = current
+                with obs.span("device.wait"):
+                    next_tok = np.asarray(jnp.argmax(logits, axis=-1))
+                eos_exits, budget_exits = [], []
+                tok_vec = np.zeros((self.n_slots,), np.int32)
+                for b in live:
+                    t = int(next_tok[b])
+                    out[b].append(t)
+                    self.tokens[b].append(t)
+                    if t == self.eos_id:
+                        eos_exits.append(b)     # EOS: no decode for this token
+                        continue
+                    tok_vec[b] = t
+                    remaining[b] -= 1
+                    if remaining[b] <= 0:
+                        budget_exits.append(b)  # budget: commit *after* this decode
+                if eos_exits:
+                    committed = self._commit_bundle(current, committed, eos_exits)
+                    live = [b for b in live if b not in eos_exits]
+                    if not live:
+                        break
+                logits2, state2 = self._decode_jit(
+                    self.params, state, jnp.asarray(tok_vec), pos)
+                live_mask = np.zeros((self.n_slots,), bool)
+                live_mask[live] = True
+                pos2 = pos + jnp.asarray(live_mask, jnp.int32)
+                current = (state2, pos2, logits2)
+                if budget_exits:
+                    committed = self._commit_bundle(current, committed, budget_exits)
+                    live = [b for b in live if b not in budget_exits]
+            self._set_bundle(committed)
+            with obs.span("device.wait"):
+                jax.block_until_ready(self._last_logits)
+        self.stats.decode_time += sp.seconds
         self.stats.decodes += sum(len(v) for v in out.values())
         return [out[int(b)] for b in slots]
 
@@ -228,7 +231,8 @@ class BatchedServeEngine:
         """Logits for the slot's *next* token given its current context —
         the batched form of ServeEngine.peek_logits (KNN-LM interpolation)."""
         assert self.active[slot], f"peek_logits of idle slot {slot}"
-        return np.asarray(self._last_logits[slot])
+        with obs.span("engine.logits_fetch"), obs.span("device.wait"):
+            return np.asarray(self._last_logits[slot])
 
     def advance(self, slots: Sequence[int], toks: Sequence[int]) -> None:
         """Append one externally-chosen token per given slot (KNN-LM: the
@@ -240,22 +244,23 @@ class BatchedServeEngine:
         slots = [int(b) for b in slots]
         assert all(self.active[b] for b in slots), \
             f"advance over idle slot(s): {[b for b in slots if not self.active[b]]}"
-        t0 = time.perf_counter()
-        state, pos, logits = self._bundle()
-        tok_vec = np.zeros((self.n_slots,), np.int32)
-        for b, t in zip(slots, toks):
-            t = int(t)
-            self.tokens[b].append(t)
-            tok_vec[b] = t
-        logits2, state2 = self._decode_jit(self.params, state,
-                                           jnp.asarray(tok_vec), pos)
-        mask = np.zeros((self.n_slots,), bool)
-        mask[slots] = True
-        pos2 = pos + jnp.asarray(mask, jnp.int32)
-        self._set_bundle(self._commit_bundle((state2, pos2, logits2),
-                                             self._bundle(), slots))
-        jax.block_until_ready(self._last_logits)
-        self.stats.decode_time += time.perf_counter() - t0
+        with obs.span("engine.decode") as sp:
+            state, pos, logits = self._bundle()
+            tok_vec = np.zeros((self.n_slots,), np.int32)
+            for b, t in zip(slots, toks):
+                t = int(t)
+                self.tokens[b].append(t)
+                tok_vec[b] = t
+            logits2, state2 = self._decode_jit(self.params, state,
+                                               jnp.asarray(tok_vec), pos)
+            mask = np.zeros((self.n_slots,), bool)
+            mask[slots] = True
+            pos2 = pos + jnp.asarray(mask, jnp.int32)
+            self._set_bundle(self._commit_bundle((state2, pos2, logits2),
+                                                 self._bundle(), slots))
+            with obs.span("device.wait"):
+                jax.block_until_ready(self._last_logits)
+        self.stats.decode_time += sp.seconds
         self.stats.decodes += len(slots)
 
     # ---- per-slot views ---------------------------------------------------------------

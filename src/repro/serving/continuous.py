@@ -34,8 +34,9 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
+from repro import obs
 from repro.core.ralmspec import ServeResult
 from repro.serving.fleet import FleetServer
 
@@ -96,6 +97,10 @@ class ContinuousResult:
     degraded_rounds: int = 0
     worker_crashes: int = 0
     shed: int = 0
+    # the span ledger's change over this serve() call (repro.obs): name ->
+    # (count, total_s, self_s), and counter name -> count
+    spans: Dict[str, tuple] = field(default_factory=dict)
+    counters: Dict[str, int] = field(default_factory=dict)
 
     @property
     def degraded_requests(self) -> int:
@@ -165,6 +170,7 @@ class ContinuousFleetServer(FleetServer):
         m0, ms0 = self.merged_rows, self.merged_rows_saved
         r0e, r0o, r0f = r.stats.errors, r.stats.timeouts, r.stats.failed_calls
         sf0 = self.seed_failures
+        snap = obs.snapshot()
         out = ContinuousResult()
         states = {}                         # slot -> RequestState (live only)
         done = {}                           # rid  -> RequestState (retired)
@@ -187,27 +193,29 @@ class ContinuousFleetServer(FleetServer):
             # participant list) — join it first; a no-op in the current
             # design, where _run_round drains its own call before returning
             self._drain_inflight()
-            unseeded = []
-            free = eng.free_slots()
-            while queue and free and queue[0].arrival <= clock:
-                rq = queue.popleft()
-                b = free.pop(0)
-                st = self._new_request_state(rid=rq.rid, max_new=rq.max_new)
-                st.arrival, st.admitted = rq.arrival, clock
-                eng.admit(b, list(rq.prompt)[-rcfg.max_prompt_len:])
-                states[b] = st
-                if rq.rid in self._preseed:  # seeded by an earlier round's call
-                    self.workload.seed_from_merged(self, st,
-                                                   *self._preseed.pop(rq.rid))
-                    st.res.kb_calls += 1
-                    st.res.kb_queries += 1
-                else:
-                    unseeded.append((b, st))
-            if unseeded:
-                # Algorithm 1 line 4, batched across the admission wave: ONE
-                # KB call seeds every newly admitted un-preseeded slot's cache
-                clock += self._seed_slots(unseeded)
-                out.seed_calls += 1
+            with obs.span("serve.admit"):
+                unseeded = []
+                free = eng.free_slots()
+                while queue and free and queue[0].arrival <= clock:
+                    rq = queue.popleft()
+                    b = free.pop(0)
+                    st = self._new_request_state(rid=rq.rid, max_new=rq.max_new)
+                    st.arrival, st.admitted = rq.arrival, clock
+                    st.res.t_admit = time.perf_counter()
+                    eng.admit(b, list(rq.prompt)[-rcfg.max_prompt_len:])
+                    states[b] = st
+                    if rq.rid in self._preseed:  # seeded by an earlier round's call
+                        self.workload.seed_from_merged(self, st,
+                                                       *self._preseed.pop(rq.rid))
+                        st.res.kb_calls += 1
+                        st.res.kb_queries += 1
+                    else:
+                        unseeded.append((b, st))
+                if unseeded:
+                    # Algorithm 1 line 4, batched across the admission wave: ONE
+                    # KB call seeds every newly admitted un-preseeded slot's cache
+                    clock += self._seed_slots(unseeded)
+                    out.seed_calls += 1
             out.max_live = max(out.max_live, len(states))
 
             # ---- one speculation round over the currently live slot set ----
@@ -223,16 +231,18 @@ class ContinuousFleetServer(FleetServer):
 
             # ---- retire finished slots (frees them for the next admit) -----
             self._drain_inflight()
-            for b in sorted(states):
-                st = states[b]
-                if self._slot_done(b, st) and not st.carry:
-                    st.finished = clock
-                    st.res.tokens = list(eng.generated(b))
-                    st.res.analytic_time = clock - st.arrival
-                    st.res.wall_time = time.perf_counter() - t0
-                    done[st.rid] = st
-                    eng.retire(b)
-                    del states[b]
+            with obs.span("serve.retire"):
+                for b in sorted(states):
+                    st = states[b]
+                    if self._slot_done(b, st) and not st.carry:
+                        st.finished = clock
+                        st.res.tokens = list(eng.generated(b))
+                        st.res.analytic_time = clock - st.arrival
+                        st.res.t_retire = time.perf_counter()
+                        st.res.wall_time = st.res.t_retire - t0
+                        done[st.rid] = st
+                        eng.retire(b)
+                        del states[b]
 
         out.wall_time = time.perf_counter() - t0
         out.analytic_time = clock
@@ -256,6 +266,7 @@ class ContinuousFleetServer(FleetServer):
             out.results.append(st.res)
             if st.res.status != "shed":
                 out.latencies.append(st.finished - st.arrival)
+        out.spans, out.counters = obs.since(snap)
         return out
 
     def _shed_overloaded(self, queue, done, out, clock: float,

@@ -15,14 +15,14 @@ requests and the jit cache stays small.
 """
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.model import Model
 
 
@@ -102,15 +102,16 @@ class ServeEngine:
         self._prefill()
 
     def _prefill(self) -> None:
-        t0 = time.perf_counter()
-        seq = list(self.doc) + self.tokens
-        toks = jnp.asarray(np.asarray(seq, np.int32))[None]
-        last, state, pos = self._prefill_jit(self.params, toks)
-        self._last_logits = last
-        self._state = state
-        self._pos = pos
-        jax.block_until_ready(last)
-        self.stats.prefill_time += time.perf_counter() - t0
+        with obs.span("engine.prefill") as sp:
+            seq = list(self.doc) + self.tokens
+            toks = jnp.asarray(np.asarray(seq, np.int32))[None]
+            last, state, pos = self._prefill_jit(self.params, toks)
+            self._last_logits = last
+            self._state = state
+            self._pos = pos
+            with obs.span("device.wait"):
+                jax.block_until_ready(last)
+        self.stats.prefill_time += sp.seconds
         self.stats.prefills += 1
 
     def set_doc(self, doc: Sequence[int]) -> None:
@@ -124,38 +125,44 @@ class ServeEngine:
     # ---- generation -------------------------------------------------------------------
     def gen(self, k: int) -> List[int]:
         """Greedy-decode up to k tokens (stops at EOS). Returns the new tokens."""
-        t0 = time.perf_counter()
-        out = []
-        logits = self._last_logits
-        for _ in range(k):
-            tok = int(jnp.argmax(logits[0]))
-            out.append(tok)
-            self.tokens.append(tok)
-            if tok == self.eos_id:
-                break
-            logits, self._state = self._decode_jit(
-                self.params, self._state, jnp.asarray([tok], jnp.int32), self._pos)
-            self._pos = self._pos + 1
-            self._last_logits = logits
-        jax.block_until_ready(self._last_logits)
-        self.stats.decode_time += time.perf_counter() - t0
+        with obs.span("engine.decode") as sp:
+            out = []
+            logits = self._last_logits
+            for _ in range(k):
+                with obs.span("device.wait"):
+                    tok = int(jnp.argmax(logits[0]))
+                out.append(tok)
+                self.tokens.append(tok)
+                if tok == self.eos_id:
+                    break
+                logits, self._state = self._decode_jit(
+                    self.params, self._state, jnp.asarray([tok], jnp.int32),
+                    self._pos)
+                self._pos = self._pos + 1
+                self._last_logits = logits
+            with obs.span("device.wait"):
+                jax.block_until_ready(self._last_logits)
+        self.stats.decode_time += sp.seconds
         self.stats.decodes += len(out)
         return out
 
     def peek_logits(self) -> np.ndarray:
         """Logits for the *next* token given the current context (KNN-LM interp)."""
-        return np.asarray(self._last_logits[0])
+        with obs.span("engine.logits_fetch"), obs.span("device.wait"):
+            return np.asarray(self._last_logits[0])
 
     def advance(self, tok: int) -> None:
         """Append an externally-chosen token (KNN-LM: interpolated argmax)."""
-        t0 = time.perf_counter()
-        self.tokens.append(int(tok))
-        logits, self._state = self._decode_jit(
-            self.params, self._state, jnp.asarray([int(tok)], jnp.int32), self._pos)
-        self._pos = self._pos + 1
-        self._last_logits = logits
-        jax.block_until_ready(logits)
-        self.stats.decode_time += time.perf_counter() - t0
+        with obs.span("engine.decode") as sp:
+            self.tokens.append(int(tok))
+            logits, self._state = self._decode_jit(
+                self.params, self._state, jnp.asarray([int(tok)], jnp.int32),
+                self._pos)
+            self._pos = self._pos + 1
+            self._last_logits = logits
+            with obs.span("device.wait"):
+                jax.block_until_ready(logits)
+        self.stats.decode_time += sp.seconds
         self.stats.decodes += 1
 
     @property

@@ -59,9 +59,10 @@ from __future__ import annotations
 
 import time
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
+from repro import obs
 from repro.configs.base import RaLMConfig
 from repro.core.cache import SharedRetrievalCache
 from repro.core.ralmspec import (RequestState, ServeResult, _ServerBase,
@@ -99,9 +100,10 @@ class FleetResult:
     seed_failures: int = 0
     degraded_rounds: int = 0
     worker_crashes: int = 0
-    # measured wall-clock overlap ledger (monotonic clock, this box — NOT the
-    # modeled timeline): total wall seconds spent inside merged verification
-    # KB calls (verify_wall_s — accumulated in sync AND async rounds), wall
+    # measured wall-clock overlap ledger (the spans' perf_counter clock — NOT
+    # the modeled timeline): total wall seconds spent inside merged
+    # verification KB calls (verify_wall_s, the ``verify.call`` spans —
+    # accumulated in sync AND async rounds), wall
     # seconds of the overlapped speculation strides the main thread ran while
     # a call was in flight (overlap_wall_s), and the intersection of the two
     # span sets (measured_overlap_s) — the seconds during which the worker's
@@ -112,6 +114,10 @@ class FleetResult:
     verify_wall_s: float = 0.0
     overlap_wall_s: float = 0.0
     measured_overlap_s: float = 0.0
+    # the span ledger's change over this serve() call (repro.obs): name ->
+    # (count, total_s, self_s), and counter name -> count
+    spans: Dict[str, tuple] = field(default_factory=dict)
+    counters: Dict[str, int] = field(default_factory=dict)
 
     @property
     def degraded_requests(self) -> int:
@@ -173,9 +179,9 @@ class FleetServer(_ServerBase):
         # monotonic count of failed admission-seed calls (same diff pattern)
         self.seed_failures = 0
         # measured wall-clock overlap ledger (same diff pattern; see
-        # FleetResult). time.monotonic spans: the worker records its KB-call
-        # span in _verify_span, the round loop intersects it with the
-        # overlapped stride's span. Both numpy BLAS and XLA release the GIL,
+        # FleetResult). The worker records its KB call's span bounds in
+        # _verify_span, the round loop intersects them with the overlapped
+        # stride's span. Both numpy BLAS and XLA release the GIL,
         # so the spans genuinely interleave even on one core — a positive
         # intersection is measured (not modeled) concurrency.
         self.verify_wall = 0.0
@@ -266,18 +272,17 @@ class FleetServer(_ServerBase):
         With async rounds this body runs on the worker thread — the publish
         is what lets slot t+1's overlapped speculation hit results verified
         for slot t, and it is safe because the shared tier locks. The
-        monotonic span of the call is recorded either way (the round loop
-        intersects it with the overlapped stride to measure real
+        ``verify.call`` span of the call is recorded either way (the round
+        loop intersects it with the overlapped stride to measure real
         concurrency); reading it from the main thread is safe only after the
         future resolves."""
-        t0 = time.monotonic()
         try:
-            ids, scores = self._retrieve_guarded(queries, k)
-            self._shared_put(queries, ids, scores)
+            with obs.span("verify.call") as sp:
+                ids, scores = self._retrieve_guarded(queries, k)
+                self._shared_put(queries, ids, scores)
         finally:
-            t1 = time.monotonic()
-            self._verify_span = (t0, t1)
-            self.verify_wall += t1 - t0
+            self._verify_span = (sp.start, sp.end)
+            self.verify_wall += sp.seconds
         return ids, scores
 
     def _seed_slots(self, pairs) -> float:
@@ -403,182 +408,194 @@ class FleetServer(_ServerBase):
         ``rounds`` counter (FleetResult or ContinuousResult).
         """
         eng, r, rcfg = self.engine, self.retriever, self.rcfg
-        analytic = 0.0
-        strides = {b: max(states[b].stride(rcfg), 1) for b in live}
-        for b in live:
-            states[b].begin_round()
+        with obs.span("round"):
+            analytic = 0.0
+            strides = {b: max(states[b].stride(rcfg), 1) for b in live}
+            for b in live:
+                states[b].begin_round()
 
-        # ---- stage 1: lockstep speculation, one batched decode per sub-step -
-        while True:
-            doers = [b for b in live
-                     if len(states[b].specs) < strides[b]
-                     and not self._slot_done(b, states[b])]
-            if not doers:
-                break
-            steps, a_sub = self._lockstep_substep(doers, states)
-            # the sub-step runs batched: the fleet pays it once, every
-            # participant's OS^3 sees it as its per-step a
-            analytic += a_sub
-            for b in doers:
-                snap, q, spec, aux = steps[b]
-                states[b].record_step(snap, q, spec, a_sub, aux)
-                if states[b].os3:
-                    states[b].os3.record_speculation(a_sub)
+            # ---- stage 1: lockstep speculation, one batched decode per sub-step -
+            with obs.span("round.speculate"):
+                while True:
+                    doers = [b for b in live
+                             if len(states[b].specs) < strides[b]
+                             and not self._slot_done(b, states[b])]
+                    if not doers:
+                        break
+                    steps, a_sub = self._lockstep_substep(doers, states)
+                    # the sub-step runs batched: the fleet pays it once, every
+                    # participant's OS^3 sees it as its per-step a
+                    analytic += a_sub
+                    for b in doers:
+                        snap, q, spec, aux = steps[b]
+                        states[b].record_step(snap, q, spec, a_sub, aux)
+                        if states[b].os3:
+                            states[b].os3.record_speculation(a_sub)
 
-        participants = [b for b in live if states[b].specs]
-        if not participants:
-            return analytic, 0
+            participants = [b for b in live if states[b].specs]
+            if not participants:
+                return analytic, 0
 
-        # ---- cross-request batched verification: ONE KB call per round ------
-        # Ride-along queries (continuous batching pre-seeds queued requests'
-        # caches this way) share the same call — batched retrieval is
-        # near-constant-cost (§A.1), so they are almost free. With async
-        # rounds they attach to the in-flight call at submission time.
-        extra = self._extra_verification_queries(analytic)
-        all_queries = [q for b in participants
-                       for q in self.workload.build_verification_queries(states[b])]
-        all_queries += list(extra)
-        k = self.workload.verify_k(rcfg)
-        # in-round dedup: one KB row per UNIQUE query in the merged call;
-        # rows scatter back to slots below. The latency model sees the
-        # deduplicated width — that's the saving.
-        uniq, inv = self._dedup(all_queries)
+            # ---- cross-request batched verification: ONE KB call per round ------
+            with obs.span("round.verify"):
+                # Ride-along queries (continuous batching pre-seeds queued requests'
+                # caches this way) share the same call — batched retrieval is
+                # near-constant-cost (§A.1), so they are almost free. With async
+                # rounds they attach to the in-flight call at submission time.
+                extra = self._extra_verification_queries(analytic)
+                all_queries = [
+                    q for b in participants
+                    for q in self.workload.build_verification_queries(states[b])]
+                all_queries += list(extra)
+                k = self.workload.verify_k(rcfg)
+                # in-round dedup: one KB row per UNIQUE query in the merged call;
+                # rows scatter back to slots below. The latency model sees the
+                # deduplicated width — that's the saving.
+                uniq, inv = self._dedup(all_queries)
 
-        # adaptive overlap gate, the fleet form of the single path's rule:
-        # only pipeline when the modeled verification latency is worth hiding
-        # (ADR's cheap probes make the overlap pure downside, paper Table 4)
-        overlap: Dict[int, List[tuple]] = {}
-        overlap_a = 0.0
-        gt_u = sc_u = None
-        if self._pool is not None:
-            a_all = [a for b in participants for a in states[b].a_times]
-            a_est = sum(a_all) / max(len(a_all), 1)
-            b_est = r.stats.model_latency(len(uniq))
-            if b_est > rcfg.async_gate_ratio * a_est:
-                # ---- stage 2: overlap the call with round t+1's stride ------
-                self._verify_span = None
-                self._inflight = self._pool.submit(
-                    self._verify_merged, uniq, k)
-                t_ov0 = time.monotonic()
-                try:
-                    overlap, overlap_a = self._overlap_speculate(
-                        participants, states, strides, a_est, b_est,
-                        fut=self._inflight)
-                finally:
-                    t_ov1 = time.monotonic()
-                    # clear the handle BEFORE joining: if the worker call
-                    # raised, a still-set handle would poison _drain_inflight
-                    # and close() with the same re-raise
-                    fut, self._inflight = self._inflight, None
-                try:
-                    gt_u, sc_u = fut.result()
-                    # measured concurrency: the worker's KB-call span
-                    # (written before the future resolved — the join is the
-                    # happens-before edge) intersected with the overlapped
-                    # stride's span, both on the monotonic clock
-                    if self._verify_span is not None:
-                        v0, v1 = self._verify_span
-                        self.overlap_wall += t_ov1 - t_ov0
-                        self.overlap_measured += max(
-                            0.0, min(v1, t_ov1) - max(v0, t_ov0))
-                except RetrievalFailed:
-                    # worker crash recovery: the in-flight verification died
-                    # (RetrievalFailed after its retries; any other exception
-                    # is a bug and propagates). Discard the overlapped stride
-                    # exactly as a rollback would — restoring each slot's
-                    # first overlap snapshot rewinds the tentative steps —
-                    # then fall back to a synchronous verification round
-                    # below, which gets a fresh retry budget. The round, not
-                    # the server, dies last: only a failed *synchronous* call
-                    # degrades.
-                    fleet.worker_crashes += 1
-                    for b, steps in overlap.items():
-                        eng.restore(b, steps[0][0])
-                        states[b].res.carry_invalidations += 1
-                    overlap, overlap_a = {}, 0.0
-        if gt_u is None:                        # sync round / closed gate / fallback
-            try:
-                gt_u, sc_u = self._verify_merged(uniq, k)
-            except RetrievalFailed:
-                if not rcfg.degrade_on_failure:
-                    raise
-                # ---- graceful degradation: speculation-only round -----------
-                # The KB is unreachable for good (this round): accept every
-                # slot's speculated stride as served output — no rollback, no
-                # cache update — and mark the requests degraded, which exempts
-                # them from the byte-parity claim (shared-cache/speculation
-                # quality only; the stream stays available instead of dying).
-                # Ride-along seed queries are dropped (their requests take the
-                # dedicated seed path later); OS^3 sees no verification.
+                # adaptive overlap gate, the fleet form of the single path's rule:
+                # only pipeline when the modeled verification latency is worth hiding
+                # (ADR's cheap probes make the overlap pure downside, paper Table 4)
+                overlap: Dict[int, List[tuple]] = {}
+                overlap_a = 0.0
+                gt_u = sc_u = None
+                if self._pool is not None:
+                    a_all = [a for b in participants for a in states[b].a_times]
+                    a_est = sum(a_all) / max(len(a_all), 1)
+                    b_est = r.stats.model_latency(len(uniq))
+                    if b_est > rcfg.async_gate_ratio * a_est:
+                        # ---- stage 2: overlap the call with round t+1's stride ------
+                        self._verify_span = None
+                        self._inflight = self._pool.submit(
+                            self._verify_merged, uniq, k)
+                        try:
+                            with obs.span("round.speculate") as ov:
+                                overlap, overlap_a = self._overlap_speculate(
+                                    participants, states, strides, a_est, b_est,
+                                    fut=self._inflight)
+                        finally:
+                            # clear the handle BEFORE joining: if the worker call
+                            # raised, a still-set handle would poison _drain_inflight
+                            # and close() with the same re-raise
+                            fut, self._inflight = self._inflight, None
+                        try:
+                            gt_u, sc_u = fut.result()
+                            # measured concurrency: the worker's KB-call span
+                            # (written before the future resolved — the join is the
+                            # happens-before edge) intersected with the overlapped
+                            # stride's span, both on the perf_counter clock
+                            if self._verify_span is not None:
+                                v0, v1 = self._verify_span
+                                self.overlap_wall += ov.seconds
+                                self.overlap_measured += max(
+                                    0.0, min(v1, ov.end) - max(v0, ov.start))
+                        except RetrievalFailed:
+                            # worker crash recovery: the in-flight verification died
+                            # (RetrievalFailed after its retries; any other exception
+                            # is a bug and propagates). Discard the overlapped stride
+                            # exactly as a rollback would — restoring each slot's
+                            # first overlap snapshot rewinds the tentative steps —
+                            # then fall back to a synchronous verification round
+                            # below, which gets a fresh retry budget. The round, not
+                            # the server, dies last: only a failed *synchronous* call
+                            # degrades.
+                            fleet.worker_crashes += 1
+                            for b, steps in overlap.items():
+                                eng.restore(b, steps[0][0])
+                                states[b].res.carry_invalidations += 1
+                            overlap, overlap_a = {}, 0.0
+                if gt_u is None:                # sync round / closed gate / fallback
+                    try:
+                        gt_u, sc_u = self._verify_merged(uniq, k)
+                    except RetrievalFailed:
+                        if not rcfg.degrade_on_failure:
+                            raise
+                        # ---- graceful degradation: speculation-only round -----------
+                        # The KB is unreachable for good (this round): accept every
+                        # slot's speculated stride as served output — no rollback, no
+                        # cache update — and mark the requests degraded, which exempts
+                        # them from the byte-parity claim (shared-cache/speculation
+                        # quality only; the stream stays available instead of dying).
+                        # Ride-along seed queries are dropped (their requests take the
+                        # dedicated seed path later); OS^3 sees no verification.
+                        analytic += self._take_ft_overhead()
+                        fleet.rounds += 1
+                        fleet.degraded_rounds += 1
+                        self._absorb_extra_verification([], [])
+                        t_commit = time.perf_counter()
+                        for b in participants:
+                            st = states[b]
+                            n = len(st.specs)
+                            st.res.status = "degraded"
+                            st.res.rounds += 1
+                            st.res.spec_steps += n
+                            st.res.strides.append(n)
+                            st.res.commit_times.append(t_commit)
+                        return analytic, len(participants)
+            with obs.span("round.commit"):
+                gt_all = gt_u if inv is None else gt_u[inv]
+                sc_all = sc_u if inv is None else sc_u[inv]
+                b_model = r.stats.model_latency(len(uniq))
+                # analytic ideal (paper §4, fleet-wide): an overlapped round pays
+                # max(a_overlap, b) for the in-flight window; a plain round pays b.
+                # Failed attempts (retries/backoff, a crashed worker call) are charged
+                # on top at their modeled cost via the guarded call's accumulator.
+                analytic += max(overlap_a, b_model) if overlap_a else b_model
                 analytic += self._take_ft_overhead()
                 fleet.rounds += 1
-                fleet.degraded_rounds += 1
-                self._absorb_extra_verification([], [])
+                if extra:
+                    self._absorb_extra_verification(gt_all[-len(extra):],
+                                                    sc_all[-len(extra):])
+
+                # ---- split per slot: cache update, mismatch, carry, bookkeeping -----
+                rollbacks = []           # slots needing a correction stride
+                corrections = {}         # slot -> workload correction payload
+                off = 0
                 for b in participants:
                     st = states[b]
                     n = len(st.specs)
-                    st.res.status = "degraded"
+                    gt = gt_all[off:off + n]
+                    sc = sc_all[off:off + n]
+                    off += n
+                    m, corr = self.workload.check_and_commit(self, st, gt, sc)
+                    if st.os3:
+                        # amortized share: the batched call serves every participant
+                        st.os3.record_verification(b_model, n, m,
+                                                   n_participants=len(participants))
                     st.res.rounds += 1
                     st.res.spec_steps += n
                     st.res.strides.append(n)
-                return analytic, len(participants)
-        gt_all = gt_u if inv is None else gt_u[inv]
-        sc_all = sc_u if inv is None else sc_u[inv]
-        b_model = r.stats.model_latency(len(uniq))
-        # analytic ideal (paper §4, fleet-wide): an overlapped round pays
-        # max(a_overlap, b) for the in-flight window; a plain round pays b.
-        # Failed attempts (retries/backoff, a crashed worker call) are charged
-        # on top at their modeled cost via the guarded call's accumulator.
-        analytic += max(overlap_a, b_model) if overlap_a else b_model
-        analytic += self._take_ft_overhead()
-        fleet.rounds += 1
-        if extra:
-            self._absorb_extra_verification(gt_all[-len(extra):],
-                                            sc_all[-len(extra):])
+                    st.res.kb_calls += 1
+                    st.res.kb_queries += n
+                    if m < n:
+                        st.res.mismatches += 1
+                        if overlap.pop(b, None):
+                            # the overlapped stride speculated past a wrong step: the
+                            # restore below rewinds it along with steps m..n-1
+                            st.res.carry_invalidations += 1
+                        with obs.span("commit.rollback"):
+                            eng.restore(b, st.snaps[m])
+                            self.workload.apply_correction(self, b, st, corr)
+                        rollbacks.append(b)
+                        corrections[b] = corr
+                    elif b in overlap:
+                        st.carry = overlap.pop(b)
+                        st.res.carry_steps += len(st.carry)
+                        if st.os3:
+                            for step in st.carry:
+                                st.os3.record_speculation(step[3])
 
-        # ---- split per slot: cache update, mismatch, carry, bookkeeping -----
-        rollbacks = []           # slots needing a correction stride
-        corrections = {}         # slot -> workload correction payload
-        off = 0
-        for b in participants:
-            st = states[b]
-            n = len(st.specs)
-            gt = gt_all[off:off + n]
-            sc = sc_all[off:off + n]
-            off += n
-            m, corr = self.workload.check_and_commit(self, st, gt, sc)
-            if st.os3:
-                # amortized share: the batched call serves every participant
-                st.os3.record_verification(b_model, n, m,
-                                           n_participants=len(participants))
-            st.res.rounds += 1
-            st.res.spec_steps += n
-            st.res.strides.append(n)
-            st.res.kb_calls += 1
-            st.res.kb_queries += n
-            if m < n:
-                st.res.mismatches += 1
-                if overlap.pop(b, None):
-                    # the overlapped stride speculated past a wrong step: the
-                    # restore below rewinds it along with steps m..n-1
-                    st.res.carry_invalidations += 1
-                eng.restore(b, st.snaps[m])
-                self.workload.apply_correction(self, b, st, corr)
-                rollbacks.append(b)
-                corrections[b] = corr
-            elif b in overlap:
-                st.carry = overlap.pop(b)
-                st.res.carry_steps += len(st.carry)
-                if st.os3:
-                    for step in st.carry:
-                        st.os3.record_speculation(step[3])
-
-        # ---- corrections: ONE batched engine call for all rollbacks ---------
-        if rollbacks:
-            tc = time.perf_counter()
-            self.workload.correction_stride(self, rollbacks, states, corrections)
-            analytic += time.perf_counter() - tc
-        return analytic, len(participants)
+                # ---- corrections: ONE batched engine call for all rollbacks ---------
+                if rollbacks:
+                    tc = time.perf_counter()
+                    with obs.span("commit.rollback"):
+                        self.workload.correction_stride(self, rollbacks, states,
+                                                        corrections)
+                    analytic += time.perf_counter() - tc
+            t_commit = time.perf_counter()
+            for b in participants:
+                states[b].res.commit_times.append(t_commit)
+            return analytic, len(participants)
 
     def serve(self, prompts: Sequence[Sequence[int]],
               max_new: Optional[Sequence[int]] = None) -> FleetResult:
@@ -597,15 +614,18 @@ class FleetServer(_ServerBase):
         r0e, r0o, r0f = r.stats.errors, r.stats.timeouts, r.stats.failed_calls
         sf0 = self.seed_failures
         vw0, ow0, mo0 = self.verify_wall, self.overlap_wall, self.overlap_measured
+        snap = obs.snapshot()
         states = [self._new_request_state(
             rid=b, max_new=max_new[b] if max_new is not None else None)
             for b in range(B)]
         fleet = FleetResult(results=[st.res for st in states])
         t0 = time.perf_counter()
 
-        for b, p in enumerate(prompts):
-            eng.start(b, list(p)[-rcfg.max_prompt_len:])
-        analytic = self._seed_slots([(b, states[b]) for b in range(B)])
+        with obs.span("serve.admit"):
+            for b, p in enumerate(prompts):
+                states[b].res.t_admit = time.perf_counter()
+                eng.start(b, list(p)[-rcfg.max_prompt_len:])
+            analytic = self._seed_slots([(b, states[b]) for b in range(B)])
 
         while True:
             # NB: a slot with a pending carry is holding an UNVERIFIED
@@ -622,7 +642,8 @@ class FleetServer(_ServerBase):
             if n_part == 0:
                 break
 
-        fleet.wall_time = time.perf_counter() - t0
+        t_end = time.perf_counter()
+        fleet.wall_time = t_end - t0
         fleet.analytic_time = analytic
         fleet.kb_calls = r.stats.calls - r0c
         fleet.kb_queries = r.stats.queries - r0q
@@ -641,8 +662,10 @@ class FleetServer(_ServerBase):
         # FleetResult instead.
         for b, st in enumerate(states):
             st.res.tokens = list(eng.generated(b))
+            st.res.t_retire = t_end
             st.res.wall_time = fleet.wall_time
             st.res.analytic_time = analytic
             st.res.gen_time = eng.stats.gen_time
             st.res.retrieval_time = r.stats.time - r0t
+        fleet.spans, fleet.counters = obs.since(snap)
         return fleet

@@ -42,6 +42,7 @@ from typing import Dict, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import RaLMConfig
 from repro.core.knnlm import knn_interpolate
 from repro.core.ralmspec import first_mismatch
@@ -121,9 +122,10 @@ class IterativeRaLMWorkload(Workload):
         t_sub = time.perf_counter()
         steps = {}
         for b in doers:
-            snap = eng.snapshot(b)
-            q = srv._query_tokens(eng.tokens[b])
-            ids, _ = states[b].cache.retrieve(q, 1)
+            with obs.span("spec.query"):
+                snap = eng.snapshot(b)
+                q = srv._query_tokens(eng.tokens[b])
+                ids, _ = states[b].cache.retrieve(q, 1)
             did = int(ids[0])
             if did >= 0:
                 eng.set_doc(b, srv._doc(did))
@@ -190,12 +192,15 @@ class KNNLMWorkload(Workload):
         t_sub = time.perf_counter()
         steps, toks = {}, []
         for b in doers:
-            snap = eng.snapshot(b)
-            q = srv._query_tokens(eng.tokens[b])
-            ids, sc = states[b].cache.retrieve(q, rcfg.knn_k)
-            vals = np.where(ids >= 0, kb.values[np.maximum(ids, 0)], -1)
+            with obs.span("spec.query"):
+                snap = eng.snapshot(b)
+                q = srv._query_tokens(eng.tokens[b])
+                ids, sc = states[b].cache.retrieve(q, rcfg.knn_k)
+                vals = np.where(ids >= 0, kb.values[np.maximum(ids, 0)], -1)
             logits = eng.peek_logits(b)
-            tok = knn_interpolate(logits, vals, sc, rcfg.knn_lambda)
+            with obs.span("spec.interpolate"):
+                tok = knn_interpolate(logits, vals, sc, rcfg.knn_lambda)
+            obs.count("knn_interp.spec")
             steps[b] = (snap, q, int(tok), logits)
             toks.append(int(tok))
         eng.advance(doers, toks)
@@ -213,8 +218,10 @@ class KNNLMWorkload(Workload):
         n = len(st.specs)
         m, corr = n, None
         for i in range(n):
-            gt_tok = knn_interpolate(st.aux[i], kb.values[gt_ids[i]],
-                                     gt_scores[i], rcfg.knn_lambda)
+            with obs.span("verify.interpolate"):
+                gt_tok = knn_interpolate(st.aux[i], kb.values[gt_ids[i]],
+                                         gt_scores[i], rcfg.knn_lambda)
+            obs.count("knn_interp.verify")
             if gt_tok != int(st.specs[i]):
                 m, corr = i, int(gt_tok)
                 break
@@ -235,16 +242,18 @@ class KNNLMWorkload(Workload):
         retrieved datastore position (consecutive entries are consecutive
         training positions — spatial locality)."""
         kb, rcfg = srv.retriever.kb, srv.rcfg
-        N = kb.size
-        want = []
-        for did in ids_row:
-            did = int(did)
-            if did < 0:
-                continue
-            want.extend(range(did, min(did + rcfg.knn_prefetch_next_n + 1, N)))
-        want = [w for w in dict.fromkeys(want) if w not in cache]
-        if want:
-            cache.insert(want, kb.embeddings[want], kb.values[want])
+        with obs.span("commit.cache_insert"):
+            N = kb.size
+            want = []
+            for did in ids_row:
+                did = int(did)
+                if did < 0:
+                    continue
+                want.extend(range(did,
+                                  min(did + rcfg.knn_prefetch_next_n + 1, N)))
+            want = [w for w in dict.fromkeys(want) if w not in cache]
+            if want:
+                cache.insert(want, kb.embeddings[want], kb.values[want])
 
 
 def default_workload(rcfg: RaLMConfig) -> Workload:

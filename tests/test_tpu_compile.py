@@ -16,6 +16,8 @@ and every test here skips when no topology can be described.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,3 +121,35 @@ def test_sharded_edr_scan_compiles_over_four_chips(topo):
     assert text.count("all-gather-start") + text.count("all-gather(") >= 1
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < (4 << 20) * D * 4 // 2   # the KB really is sharded
+
+
+KERNEL_NAMES = {"dense": "dense_topk", "quant": "quant_topk",
+                "fused": "fused_gathered_topk",
+                "quant_fused": "quant_fused_gathered_topk",
+                "gathered": "gathered_topk",
+                "quant_gathered": "quant_gathered_topk"}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_NAMES))
+def test_kernel_instruction_carries_its_name(shape, kernel):
+    """A profiler trace names a kernel by its custom call's instruction
+    (``dense_topk:dense_topk.1``): the ``name=`` of the ``pallas_call``
+    sets it, whatever name scope the call sits in."""
+    fn, args = _kernel_cases(4, shape)[kernel]
+    text = _compiled_text(fn, *args)
+    names = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert names
+    assert {n.rsplit(".", 1)[0] for n in names} == {KERNEL_NAMES[kernel]}
+
+
+def test_exact_scan_scopes_its_pad_and_scan(shape):
+    """A KB off the kernel's block is padded per call: the pad and the
+    scan sit in the ``kb_pad`` and ``kb_scan`` scopes."""
+    text = _compiled_text(lambda q, kb: K.dense_topk_pallas(q, kb, TOPK),
+                          shape((4, D), jnp.float32),
+                          shape((5000, D), jnp.float32))
+    ops = dict(re.findall(r"%([\w.-]+) = [^\n]*op_name=\"([^\"]*)\"", text))
+    assert any("kb_pad/" in v and k.startswith("pad") for k, v in ops.items())
+    assert any("kb_scan/dense_topk" in v and k.startswith("dense_topk.")
+               for k, v in ops.items())
