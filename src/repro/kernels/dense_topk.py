@@ -12,7 +12,10 @@ FAISS's GPU brute-force scan becomes a single fused kernel that
     MXU/VPU-friendly for the small K regime retrieval lives in).
 
 Grid: one dimension over KB tiles. The query block is small (B ≤ 128 rows padded to
-8/128 lanes) and stays resident in VMEM for every grid step.
+8/128 lanes) and stays resident in VMEM for every grid step. The resident KB is
+never copied: when N is off the tile, the last tile is ragged, its rows past N
+hold whatever the pipeline left in VMEM, and the kernel masks every id at or
+past N to the NEG sentinel by a select, so not even a NaN there can leak.
 
 The GATHERED variant (:func:`gathered_topk_pallas`) is the ADR/IVF form of the
 same scan: instead of every KB row, query b scores only its probed buckets'
@@ -168,7 +171,7 @@ def _topk_kernel(q_ref, kb_ref, out_s_ref, out_i_ref, run_s, run_i, *,
                             preferred_element_type=jnp.float32)   # (B, block_n)
     ids = (pl.program_id(0) * block_n
            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    s = jnp.where(ids < n_total, s, NEG)                  # mask KB padding rows
+    s = jnp.where(ids < n_total, s, NEG)                  # mask the ragged tail
     _stream_merge(s, ids, out_s_ref, out_i_ref, run_s, run_i, k)
 
 
@@ -211,15 +214,15 @@ def gathered_topk_pallas(queries: jax.Array, cand_emb: jax.Array,
 
 def dense_topk_pallas(queries: jax.Array, kb: jax.Array, k: int, *,
                       block_n: int = 1024, interpret: bool = False):
-    """queries (B, d) f32; kb (N, d) f32 -> (scores (B, k), ids (B, k))."""
+    """queries (B, d) f32; kb (N, d) f32 -> (scores (B, k), ids (B, k)).
+
+    The KB is scanned in place, never copied: when N is off ``block_n`` the
+    last tile is ragged and its rows past N are masked to NEG in the kernel.
+    A KB under 128 rows is one such tile, larger than the array."""
     B, d = queries.shape
     N = kb.shape[0]
     block_n = max(min(block_n, N), 128)     # MXU-aligned tile, never tiny
     nb = -(-N // block_n)
-    pad = nb * block_n - N
-    if pad:
-        with jax.named_scope("kb_pad"):
-            kb = jnp.pad(kb, ((0, pad), (0, 0)))
 
     out_shape, out_specs, scratch = _topk_outputs(B, k)
     with jax.named_scope("kb_scan"):
@@ -251,7 +254,7 @@ def _quant_topk_kernel(q_ref, kbq_ref, scale_ref, out_s_ref, out_i_ref,
     s = s * scale_ref[...]                                # (1, block_n) scales
     ids = (pl.program_id(0) * block_n
            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    s = jnp.where(ids < n_total, s, NEG)                  # mask KB padding rows
+    s = jnp.where(ids < n_total, s, NEG)                  # mask the ragged tail
     _stream_merge(s, ids, out_s_ref, out_i_ref, run_s, run_i, k)
 
 
@@ -272,33 +275,35 @@ def quant_topk_pallas(queries: jax.Array, kb_q: jax.Array, scales: jax.Array,
                       interpret: bool = False):
     """queries (B, d) f32; kb_q (N, d) int8; scales (N,) f32
     -> (scores (B, k), ids (B, k)) of the dequantized scan
-    ``(q @ kb_q.T) * scales``."""
+    ``(q @ kb_q.T) * scales``.
+
+    The int8 KB is scanned in place, never copied: its last tile is ragged
+    when N is off ``block_n`` and is masked to NEG in the kernel. Only the
+    (N,) scales are padded (4 bytes a row), for their one-row layout."""
     B, d = queries.shape
     N = kb_q.shape[0]
     block_n = max(min(block_n, N), 128)     # MXU-aligned tile, never tiny
     nb = -(-N // block_n)
-    pad = nb * block_n - N
-    if pad:
-        kb_q = jnp.pad(kb_q, ((0, pad), (0, 0)))
-        scales = jnp.pad(scales, (0, pad))
+    scales = jnp.pad(scales, (0, nb * block_n - N))
     # scales stream as one lane-aligned (1, block_n) slice of a single row
     # per grid step: a (1, block_n) block of an (nb, block_n) array would
     # break the (8, 128) block rule
     scales = scales.reshape(1, nb * block_n)
 
     out_shape, out_specs, scratch = _topk_outputs(B, k)
-    return pl.pallas_call(
-        functools.partial(_quant_topk_kernel, k=k, block_n=block_n,
-                          n_total=N),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((B, d), lambda j: (0, 0)),          # queries resident
-            pl.BlockSpec((block_n, d), lambda j: (j, 0)),    # int8 tile stream
-            pl.BlockSpec((1, block_n), lambda j: (0, j)),    # row scales
-        ],
-        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=interpret, name="quant_topk",
-    )(queries, kb_q, scales)
+    with jax.named_scope("kb_scan"):
+        return pl.pallas_call(
+            functools.partial(_quant_topk_kernel, k=k, block_n=block_n,
+                              n_total=N),
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((B, d), lambda j: (0, 0)),      # queries resident
+                pl.BlockSpec((block_n, d), lambda j: (j, 0)),  # int8 tiles
+                pl.BlockSpec((1, block_n), lambda j: (0, j)),  # row scales
+            ],
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret, name="quant_topk",
+        )(queries, kb_q, scales)
 
 
 def quant_gathered_topk_pallas(queries: jax.Array, cand_emb: jax.Array,

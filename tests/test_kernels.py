@@ -239,6 +239,51 @@ def test_quant_topk_block_boundary_ids():
     assert list(np.asarray(i[0])) == hot
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "quant"])
+@pytest.mark.parametrize("N,block_n", [
+    (2051, 512),        # five tiles, the last holds 3 rows
+    (1500, 512),        # the last tile holds 476 of its 512 rows
+    (300, 128),         # the last tile holds 44 rows
+    (5, 1024),          # the smallest KB: one 128-row tile over 5 rows
+])
+def test_exact_scan_ragged_last_block(quant, N, block_n):
+    """A KB off the tile is scanned in place: the last tile runs past N
+    (interpret mode fills it with NaN, or -128 for int8 codes, whose padded
+    scales are 0) and only the kernel's mask keeps those rows out. The true
+    top-k sits in the last tile and straddles its start, and every other
+    row scores below zero, so a tail row that got through would outrank
+    them."""
+    d, B = 16, 3
+    tile = max(min(block_n, N), 128)
+    start = (-(-N // tile) - 1) * tile
+    hot = sorted({r for r in (start - 1, start, (start + N - 1) // 2, N - 1)
+                  if r >= 0})
+    k = min(len(hot) + 2, N)
+    g = np.random.default_rng(N)
+    kb = (0.1 * g.standard_normal((N, d))).astype(np.float32)
+    kb[:, 0] = -1.0
+    for rank, row in enumerate(hot):
+        kb[row, 0] = 10.0 - rank
+    q = g.standard_normal((B, d)).astype(np.float32)
+    q[:, 0] = 4.0
+    q = jnp.asarray(q)
+    if quant:
+        codes, scales = quantize_kb(kb)
+        s_k, i_k = quant_topk_pallas(q, jnp.asarray(codes),
+                                     jnp.asarray(scales), k, block_n=block_n,
+                                     interpret=True)
+        s_r, i_r = ref.quant_dense_topk_ref(q, jnp.asarray(codes),
+                                            jnp.asarray(scales), k)
+    else:
+        s_k, i_k = dense_topk_pallas(q, jnp.asarray(kb), k, block_n=block_n,
+                                     interpret=True)
+        s_r, i_r = ref.dense_topk_ref(q, jnp.asarray(kb), k)
+    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_r), atol=1e-4,
+                               rtol=1e-4)
+    assert np.array_equal(np.asarray(i_k), np.asarray(i_r))
+    assert np.all(np.asarray(i_k)[:, :len(hot)] == hot)
+
+
 @pytest.mark.parametrize("B,H,KV,hd,W,cl", [
     (1, 4, 4, 32, 64, 64), (2, 8, 2, 32, 300, 123), (4, 16, 8, 64, 1024, 1000),
     (1, 8, 1, 128, 129, 57),

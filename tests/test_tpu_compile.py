@@ -143,13 +143,25 @@ def test_kernel_instruction_carries_its_name(shape, kernel):
     assert {n.rsplit(".", 1)[0] for n in names} == {KERNEL_NAMES[kernel]}
 
 
-def test_exact_scan_scopes_its_pad_and_scan(shape):
-    """A KB off the kernel's block is padded per call: the pad and the
-    scan sit in the ``kb_pad`` and ``kb_scan`` scopes."""
-    text = _compiled_text(lambda q, kb: K.dense_topk_pallas(q, kb, TOPK),
-                          shape((4, D), jnp.float32),
-                          shape((5000, D), jnp.float32))
+@pytest.mark.parametrize("kernel", ["dense", "quant"])
+def test_exact_scan_reads_an_off_block_kb_in_place(shape, kernel):
+    """At the KNN-LM cell's 806,461 x 1024 KB (off the 1024-row block) the
+    exact scans read the resident KB as it is: the kernel sits in the
+    ``kb_scan`` scope, no pad op copies the KB, and the program holds no
+    temporary of the KB's size (a per-call pad held 3,305,143,808 bytes)."""
+    n, d, kb_dt = 806_461, 1024, jnp.int8 if kernel == "quant" else jnp.float32
+    q, kb = shape((8, d), jnp.float32), shape((n, d), kb_dt)
+    if kernel == "quant":
+        compiled = jax.jit(lambda q, kb, s: K.quant_topk_pallas(q, kb, s, 8)
+                           ).lower(q, kb, shape((n,), jnp.float32)).compile()
+    else:
+        compiled = jax.jit(lambda q, kb: K.dense_topk_pallas(q, kb, 8)
+                           ).lower(q, kb).compile()
+    text = compiled.as_text()
     ops = dict(re.findall(r"%([\w.-]+) = [^\n]*op_name=\"([^\"]*)\"", text))
-    assert any("kb_pad/" in v and k.startswith("pad") for k, v in ops.items())
-    assert any("kb_scan/dense_topk" in v and k.startswith("dense_topk.")
+    assert any(f"kb_scan/{KERNEL_NAMES[kernel]}" in v
+               and k.startswith(f"{KERNEL_NAMES[kernel]}.")
                for k, v in ops.items())
+    pads = re.findall(r"%pad[\w.-]* = \w+\[([\d,]*)\]", text)
+    assert not [p for p in pads if p.endswith(f",{d}")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
